@@ -163,7 +163,7 @@ _PIPELINE_SCRIPT = r"""
 import json, sys
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.engine import MicroEPEngine
 from repro.launch.mesh import make_local_mesh
 from repro.moe.experts import init_canonical_experts, ExpertParams
@@ -202,7 +202,7 @@ for stages in stage_list:
     fn = jax.jit(shard_map(
         inner, mesh=mesh,
         in_specs=(P(), P("data", "model"), P(("data", "model"))),
-        out_specs=P(("data", "model")), check_rep=False))
+        out_specs=P(("data", "model")), check_vma=False))
     t = time_it(lambda: jax.block_until_ready(fn(w_router, work, x)),
                 iters=iters, warmup=2)
     out_rows.append({"bench": "pipeline", "devices": g,
@@ -213,7 +213,9 @@ print("JSON:" + json.dumps(out_rows))
 
 
 def bench_pipeline_path(rows_out, smoke: bool):
-    env = dict(os.environ,
+    # the child runs a fake-device CPU mesh by design: pin it to the CPU
+    # so it never reaches for an accelerator this process may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     env.setdefault("PYTHONPATH", "src")
     r = subprocess.run(

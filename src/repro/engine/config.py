@@ -390,7 +390,8 @@ class RuntimeConfig:
                       jnp/np dtypes are normalized to the string name).
     capacity_factor — per-(src, dst) dispatch chunk head-room (§4).
     impl            — grouped-FFN kernel: 'ref' | 'interpret' | 'pallas'
-                      (None = kernel default).
+                      (None = ``kernels.ops.default_impl()``: 'pallas'
+                      on a TPU).
     remat / unroll  — layer-scan rematerialization / unrolling.
     layout          — parameter stacking: 'scan' (production) | 'list'
                       (dry-run cost pass).
@@ -419,7 +420,7 @@ class RuntimeConfig:
     policy: SchedulePolicy = SchedulePolicy()
     dtype: str = "bfloat16"
     capacity_factor: float = 2.0
-    impl: Optional[str] = "ref"
+    impl: Optional[str] = None
     remat: bool = True
     unroll: bool = False
     layout: str = "scan"
@@ -541,7 +542,9 @@ class RuntimeConfig:
         g.add_argument("--dtype", default=d.dtype, choices=_DTYPES)
         g.add_argument("--capacity-factor", type=float,
                        default=d.capacity_factor)
-        g.add_argument("--impl", default=d.impl, choices=_IMPLS)
+        g.add_argument("--impl", default=d.impl, choices=_IMPLS,
+                       help="grouped-FFN kernel (default: pallas on a TPU, "
+                            "ref elsewhere)")
         g.add_argument("--remat", action=b, default=d.remat)
         g.add_argument("--unroll", action=b, default=d.unroll)
         g.add_argument("--layout", default=d.layout, choices=_LAYOUTS)

@@ -1,26 +1,28 @@
-"""Pallas TPU kernel: grouped (per-expert-slot) gated FFN over ragged groups.
+"""Pallas TPU kernel: grouped gated FFN over ragged, flat expert groups.
 
 This is the compute hotspot of the paper's system (§7.4: expert computation
 dominates the MoE layer).  On GPU the standard answer is MegaBlocks' grouped
 GEMM; the TPU-native adaptation here:
 
-  * tokens arrive slot-grouped ``[S, C, H]`` (S = local expert slots,
-    C = static capacity) with a ragged ``counts[S]`` — the dispatcher
-    (moe/dispatch.py) produces exactly this layout;
-  * grid = (S, C/bm, F/bf): each step computes one (bm × bf) tile of the
+  * rows arrive flat ``[N, H]``, sorted by expert slot, each group starting
+    on a bm-aligned row — the dispatcher's native layout (moe/dispatch.py);
+  * grid = (N/bm, F/bf): each step computes one (bm × bf) tile of the
     hidden activation h = act(x·Wg) ⊙ (x·Wu) and accumulates h·Wd into a
-    VMEM f32 accumulator of shape (bm, H), writing back once per row-tile;
-  * tiles whose row range lies beyond ``counts[s]`` skip both matmuls via
-    ``pl.when`` — the TPU analog of MegaBlocks skipping empty blocks:
-    padded capacity costs O(1) control per tile, not O(bm·H·F) FLOPs;
-  * ``counts`` is scalar-prefetched (SMEM) so the skip decision is known
-    before the tile's DMAs are issued;
-  * all matmul dims are MXU-aligned (bm, bf multiples of 128; H, F padded
-    by the wrapper in ops.py when needed).
+    VMEM f32 accumulator of shape (bm, H), written back once per row tile;
+  * the per-tile group id and the group ends are scalar-prefetched (SMEM),
+    so each tile's weight DMAs are addressed before it runs; tiles with no
+    valid row skip both matmuls via ``pl.when`` — padded capacity costs
+    O(1) control per tile, not O(bm·H·F) FLOPs;
+  * operands enter the MXU in their own dtype (bf16 in training and
+    serving) with f32 accumulation; only h is cast back for the down dot.
 
-VMEM budget at defaults (bm=128, bf=512, H≤8192):
-  x tile bm·H·2B ≤ 2 MB, Wg/Wu tiles H·bf·2B ≤ 8 MB each, Wd tile 8 MB,
-  f32 accumulator bm·H·4B ≤ 4 MB — comfortably inside 64 MB/core.
+VMEM reckoning, bf16 at bm=128, bf=512 (every block double-buffered):
+  x tile bm·H·2B, Wg/Wu/Wd tiles H·bf·2B each, out tile bm·H·2B, plus the
+  f32 accumulator bm·H·4B and ~3 f32 (bm, bf) temporaries.  At H=2048
+  that is 1 + 12 + 1 + 1 + 0.75 ≈ 16 MiB — just over the 16 MiB default
+  scoped-VMEM limit of a v5e core, so the kernel asks for ``_VMEM_LIMIT``
+  (64 MiB of the core's 128 MiB).  That covers H up to 6144 (≈ 46 MiB).
+  F only sets the number of f-steps, so F=1024 and F=8192 need the same.
 """
 from __future__ import annotations
 
@@ -31,51 +33,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["grouped_ffn_pallas"]
+from .ref import gated_act
 
+__all__ = ["grouped_ffn_flat_pallas"]
 
-def _ffn_kernel(counts_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref,
-                *, activation: str, bm: int, nf: int):
-    s = pl.program_id(0)
-    row_tile = pl.program_id(1)
-    f_tile = pl.program_id(2)
-
-    count = counts_ref[s]
-    row_active = row_tile * bm < count  # any valid row in this tile
-
-    @pl.when(f_tile == 0)
-    def _zero_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(row_active)
-    def _compute():
-        x = x_ref[0].astype(jnp.float32)            # (bm, H)
-        # mask rows beyond the group's count so junk never enters the MXU
-        rows = row_tile * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-        x = jnp.where(rows < count, x, 0.0)
-        wg = wg_ref[0].astype(jnp.float32)          # (H, bf)
-        wu = wu_ref[0].astype(jnp.float32)          # (H, bf)
-        hg = jax.lax.dot(x, wg)
-        hu = jax.lax.dot(x, wu)
-        if activation == "geglu":
-            h = jax.nn.gelu(hg) * hu
-        elif activation == "swiglu":
-            h = jax.nn.silu(hg) * hu
-        else:  # relu_sq
-            h = jnp.square(jnp.maximum(hg, 0.0)) * hu
-        wd = wd_ref[0].astype(jnp.float32)          # (bf, H)
-        acc_ref[...] += jax.lax.dot(h, wd)
-
-    @pl.when(f_tile == nf - 1)
-    def _write():
-        out = jnp.where(row_active, acc_ref[...], 0.0)
-        o_ref[0] = out.astype(o_ref.dtype)
+_VMEM_LIMIT = 64 * 2 ** 20
 
 
 def _ffn_flat_kernel(meta_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref,
                      *, activation: str, bm: int, nf: int):
-    """Flat MegaBlocks-style variant: rows pre-sorted by group with bm-aligned
-    group starts; meta_ref holds [gid_per_tile (NT) | group_end (S)]."""
+    """meta_ref holds [gid_per_tile (NT) | group_end (S)]."""
     row_tile = pl.program_id(0)
     f_tile = pl.program_id(1)
     nt = pl.num_programs(0)
@@ -90,21 +57,15 @@ def _ffn_flat_kernel(meta_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref,
 
     @pl.when(row_active)
     def _compute():
-        x = x_ref[...].astype(jnp.float32)          # (bm, H)
+        x = x_ref[...]                              # (bm, H)
+        # mask rows beyond the group's end so junk never enters the MXU
         rows = row_tile * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-        x = jnp.where(rows < end, x, 0.0)
-        wg = wg_ref[0].astype(jnp.float32)
-        wu = wu_ref[0].astype(jnp.float32)
-        hg = jax.lax.dot(x, wg)
-        hu = jax.lax.dot(x, wu)
-        if activation == "geglu":
-            h = jax.nn.gelu(hg) * hu
-        elif activation == "swiglu":
-            h = jax.nn.silu(hg) * hu
-        else:
-            h = jnp.square(jnp.maximum(hg, 0.0)) * hu
-        wd = wd_ref[0].astype(jnp.float32)
-        acc_ref[...] += jax.lax.dot(h, wd)
+        x = jnp.where(rows < end, x, jnp.zeros_like(x))
+        hg = jax.lax.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        hu = jax.lax.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        h = gated_act(hg, hu, activation).astype(wd_ref.dtype)
+        acc_ref[...] += jax.lax.dot(h, wd_ref[0],
+                                    preferred_element_type=jnp.float32)
 
     @pl.when(f_tile == nf - 1)
     def _write():
@@ -152,47 +113,9 @@ def grouped_ffn_flat_pallas(
             scratch_shapes=[pltpu.VMEM((bm, h), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n, h), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="grouped_ffn",
     )(meta, x, w_gate, w_up, w_down)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("activation", "bm", "bf", "interpret"),
-)
-def grouped_ffn_pallas(
-    x: jax.Array,        # [S, C, H]
-    counts: jax.Array,   # int32[S]
-    w_gate: jax.Array,   # [S, H, F]
-    w_up: jax.Array,     # [S, H, F]
-    w_down: jax.Array,   # [S, F, H]
-    activation: str = "swiglu",
-    bm: int = 128,
-    bf: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    s, c, h = x.shape
-    f = w_gate.shape[-1]
-    assert c % bm == 0, (c, bm)
-    assert f % bf == 0, (f, bf)
-    nf = f // bf
-
-    grid = (s, c // bm, nf)
-    kernel = functools.partial(_ffn_kernel, activation=activation, bm=bm, nf=nf)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,  # counts
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bm, h), lambda s_, i, j, counts: (s_, i, 0)),
-                pl.BlockSpec((1, h, bf), lambda s_, i, j, counts: (s_, 0, j)),
-                pl.BlockSpec((1, h, bf), lambda s_, i, j, counts: (s_, 0, j)),
-                pl.BlockSpec((1, bf, h), lambda s_, i, j, counts: (s_, j, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bm, h), lambda s_, i, j, counts: (s_, i, 0)),
-            scratch_shapes=[pltpu.VMEM((bm, h), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((s, c, h), x.dtype),
-        interpret=interpret,
-    )(counts, x, w_gate, w_up, w_down)

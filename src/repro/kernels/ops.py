@@ -1,17 +1,20 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On a real TPU the wrappers call the Pallas kernels compiled natively; on CPU
-(this container) they run either in Pallas ``interpret=True`` mode (tests) or
-fall back to the jnp oracle (fast path for CPU training examples).  The
-switch is explicit, never silent: callers pick via ``impl=``.
+``impl`` picks the implementation: 'pallas' (the kernel compiled natively),
+'interpret' (the same kernel in Pallas interpret mode — CPU tests) or
+'ref' (the jnp oracle of kernels/ref.py).  ``impl=None`` resolves once, in
+:func:`default_impl`, from the backend: 'pallas' on a TPU, so no device
+path ever falls back to the oracle without saying so.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from . import ref
-from .grouped_matmul import grouped_ffn_flat_pallas, grouped_ffn_pallas
+from .grouped_matmul import grouped_ffn_flat_pallas
 from .wkv6_chunk import wkv6_pallas
 
 __all__ = ["grouped_ffn", "grouped_ffn_flat", "grouped_ffn_flat_chunked",
@@ -51,19 +54,23 @@ def grouped_ffn(
     bm: int = 128,
     bf: int = 512,
 ) -> jax.Array:
-    """Ragged per-slot gated FFN.  x: [S, C, H] -> [S, C, H]."""
+    """Ragged per-slot gated FFN.  x: [S, C, H] -> [S, C, H].
+
+    The Pallas path runs the flat kernel on the slots laid end to end:
+    slot s starts at row s·C (C padded to a bm multiple) and ends at
+    s·C + counts[s]."""
     impl = impl or default_impl()
     if impl == "ref":
         return ref.grouped_ffn_ref(x, counts, w_gate, w_up, w_down, activation)
-    interpret = impl == "interpret"
-    c0 = x.shape[1]
+    s, c0, h = x.shape
     xp = _pad_axis(x, 1, bm)
-    wgp, wup, wdp = _pad_ffn_weights(w_gate, w_up, w_down, bf)
-    out = grouped_ffn_pallas(
-        xp, counts, wgp, wup, wdp,
-        activation=activation, bm=bm, bf=bf, interpret=interpret,
-    )
-    return out[:, :c0, :]
+    c = xp.shape[1]
+    start = jnp.arange(s, dtype=jnp.int32) * c
+    out = grouped_ffn_flat(xp.reshape(s * c, h), start,
+                           start + counts.astype(jnp.int32),
+                           w_gate, w_up, w_down, activation=activation,
+                           impl=impl, bm=bm, bf=bf)
+    return out.reshape(s, c, h)[:, :c0, :]
 
 
 def grouped_ffn_flat(
@@ -86,13 +93,43 @@ def grouped_ffn_flat(
         )
     wgp, wup, wdp = _pad_ffn_weights(w_gate, w_up, w_down, bf)
     return _flat_padded(x, group_start, group_end, wgp, wup, wdp,
-                        activation=activation, bm=bm, bf=bf,
-                        interpret=(impl == "interpret"))
+                        activation, bm, bf, impl == "interpret")
 
 
-def _flat_padded(x, group_start, group_end, wgp, wup, wdp, *,
+def _row_groups(n: int, group_start, group_end):
+    """Ragged-dot group sizes covering all N rows (group g owns rows from
+    its start to the next group's start, padding included) and the bool[N]
+    mask of rows inside some group's [start, end) — the kernel's mask."""
+    bounds = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              group_start[1:].astype(jnp.int32),
+                              jnp.full((1,), n, jnp.int32)])
+    sizes = jnp.diff(bounds)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    gid = jnp.clip(jnp.searchsorted(group_start, rows, side="right") - 1,
+                   0, group_start.shape[0] - 1)
+    valid = (rows >= group_start[gid]) & (rows < group_end[gid])
+    return sizes, valid
+
+
+def _ragged_ffn(x, sizes, valid, wg, wu, wd, activation):
+    """The flat grouped FFN as three ``lax.ragged_dot``s: O(N·H·F)."""
+    xm = jnp.where(valid[:, None], x, jnp.zeros_like(x))
+    hg = jax.lax.ragged_dot(xm, wg, sizes, preferred_element_type=jnp.float32)
+    hu = jax.lax.ragged_dot(xm, wu, sizes, preferred_element_type=jnp.float32)
+    h = ref.gated_act(hg, hu, activation).astype(wd.dtype)
+    out = jax.lax.ragged_dot(h, wd, sizes, preferred_element_type=jnp.float32)
+    return out.astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _flat_padded(x, group_start, group_end, wgp, wup, wdp,
                  activation, bm, bf, interpret):
-    """Pallas flat call on already-padded weights (chunk-range inner)."""
+    """Pallas flat call on already-padded weights (chunk-range inner).
+
+    Forward: the Pallas kernel.  Backward: the vjp of :func:`_ragged_ffn`
+    (forward recomputed with ``lax.ragged_dot``), ragged like the kernel —
+    O(rows·H·F), never the dense oracle.  A Pallas backward is later work.
+    """
     n = x.shape[0]
     s = wgp.shape[0]
     # tile group ids from the (bm-aligned) starts
@@ -104,6 +141,28 @@ def _flat_padded(x, group_start, group_end, wgp, wup, wdp, *,
         x, tile_gid, group_end, wgp, wup, wdp,
         activation=activation, bm=bm, bf=bf, interpret=interpret,
     )
+
+
+def _flat_padded_fwd(x, group_start, group_end, wgp, wup, wdp,
+                     activation, bm, bf, interpret):
+    out = _flat_padded(x, group_start, group_end, wgp, wup, wdp,
+                       activation, bm, bf, interpret)
+    return out, (x, group_start, group_end, wgp, wup, wdp)
+
+
+def _flat_padded_bwd(activation, bm, bf, interpret, res, g):
+    x, group_start, group_end, wgp, wup, wdp = res
+    with jax.named_scope("grouped_ffn_bwd"):
+        sizes, valid = _row_groups(x.shape[0], group_start, group_end)
+        _, vjp = jax.vjp(
+            lambda x_, wg, wu, wd: _ragged_ffn(x_, sizes, valid, wg, wu, wd,
+                                               activation),
+            x, wgp, wup, wdp)
+        dx, dwg, dwu, dwd = vjp(g)
+    return dx, None, None, dwg, dwu, dwd
+
+
+_flat_padded.defvjp(_flat_padded_fwd, _flat_padded_bwd)
 
 
 def grouped_ffn_flat_chunked(
@@ -135,8 +194,7 @@ def grouped_ffn_flat_chunked(
     wgp, wup, wdp = _pad_ffn_weights(w_gate, w_up, w_down, bf)
     return tuple(
         _flat_padded(xc, group_starts[c], group_ends[c], wgp, wup, wdp,
-                     activation=activation, bm=bm, bf=bf,
-                     interpret=(impl == "interpret"))
+                     activation, bm, bf, impl == "interpret")
         for c, xc in enumerate(x_chunks))
 
 

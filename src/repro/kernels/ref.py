@@ -9,10 +9,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["grouped_ffn_ref", "grouped_matmul_ref", "wkv6_chunk_ref"]
+__all__ = ["gated_act", "grouped_ffn_ref", "grouped_matmul_ref",
+           "wkv6_chunk_ref"]
 
 
-def _act(h_gate, h_up, activation: str):
+def gated_act(h_gate, h_up, activation: str):
     if activation == "geglu":
         return jax.nn.gelu(h_gate) * h_up
     if activation == "swiglu":
@@ -39,7 +40,7 @@ def grouped_ffn_ref(
     wd = w_down.astype(jnp.float32)
     hg = jnp.einsum("sch,shf->scf", xm, wg)
     hu = jnp.einsum("sch,shf->scf", xm, wu)
-    act = _act(hg, hu, activation)
+    act = gated_act(hg, hu, activation)
     out = jnp.einsum("scf,sfh->sch", act, wd)
     return jnp.where(mask, out, 0).astype(x.dtype)
 
@@ -65,7 +66,7 @@ def grouped_ffn_flat_ref(
     xf = x.astype(jnp.float32)
     hg = jnp.einsum("nh,shf->snf", xf, w_gate.astype(jnp.float32))
     hu = jnp.einsum("nh,shf->snf", xf, w_up.astype(jnp.float32))
-    act = _act(hg, hu, activation)
+    act = gated_act(hg, hu, activation)
     out_s = jnp.einsum("snf,sfh->snh", act, w_down.astype(jnp.float32))
     out = jnp.einsum("sn,snh->nh", member.astype(jnp.float32), out_s)
     return out.astype(x.dtype)

@@ -45,48 +45,45 @@ def _wkv6_kernel(q_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref,
     def _reset():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    q = q_ref[0].astype(jnp.float32)      # (chunk, D)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    lw = lw_ref[0].astype(jnp.float32)    # (chunk, D) log-decay (<= 0)
-    u = u_ref[0].astype(jnp.float32)      # (1, D) in block form -> (D,)
-    u_vec = u[0] if u.ndim == 2 else u
+    u = u_ref[0].astype(jnp.float32)                  # (1, D)
+    f32 = jnp.float32
+    # lower-triangular ones: prefix sums over a sub-chunk as one matmul
+    r_ids = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    c_ids = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    tril = (r_ids >= c_ids).astype(f32)
+    causal = r_ids > c_ids                            # strictly causal
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (d, d), 1))
 
-    nsub = chunk // sub
-
-    def sub_step(i, carry):
-        s_in, o_acc = carry
-        sl = i * sub
-        qs = jax.lax.dynamic_slice(q, (sl, 0), (sub, d))
-        ks = jax.lax.dynamic_slice(k, (sl, 0), (sub, d))
-        vs = jax.lax.dynamic_slice(v, (sl, 0), (sub, d))
-        lws = jax.lax.dynamic_slice(lw, (sl, 0), (sub, d))
-        c = jnp.cumsum(lws, axis=0)                       # c_t, t=1..sub
-        c_prev = c - lws                                  # c_{t-1}
+    def sub_step(i, s_in):
+        sl = pl.multiple_of(i * sub, sub)
+        rows = (0, pl.ds(sl, sub), slice(None))
+        qs = q_ref[rows].astype(f32)                  # (τ, D)
+        ks = k_ref[rows].astype(f32)
+        vs = v_ref[rows].astype(f32)
+        lws = lw_ref[rows].astype(f32)
+        c = jax.lax.dot(tril, lws,                    # c_t, t=1..τ
+                        precision=jax.lax.Precision.HIGHEST)
+        c_prev = c - lws                              # c_{t-1}
         # cross-subchunk: (τ, D) x (D, D)
-        q_dec = qs * jnp.exp(c_prev)
-        o_sub = jax.lax.dot(q_dec, s_in)
+        o_sub = jax.lax.dot(qs * jnp.exp(c_prev), s_in)
         # intra-subchunk, strictly causal, per-dim bounded exponents
-        expo = c_prev[:, None, :] - c[None, :, :]         # (τ, τ, D)
-        tri = (jnp.arange(sub)[:, None] > jnp.arange(sub)[None, :])
-        amat = jnp.where(tri[..., None], jnp.exp(jnp.minimum(expo, 0.0)), 0.0)
+        expo = c_prev[:, None, :] - c[None, :, :]     # (τ, τ, D)
+        amat = jnp.exp(jnp.minimum(expo, 0.0))
         score = jnp.sum(qs[:, None, :] * ks[None, :, :] * amat, axis=-1)
+        score = jnp.where(causal, score, 0.0)
         o_sub += jax.lax.dot(score, vs)
         # current-token bonus
-        diag = jnp.sum(qs * (u_vec[None, :] * ks), axis=-1, keepdims=True)
-        o_sub += diag * vs
-        o_acc = jax.lax.dynamic_update_slice(o_acc, o_sub, (sl, 0))
+        o_sub += jnp.sum(qs * u * ks, axis=-1, keepdims=True) * vs
+        o_ref[rows] = o_sub.astype(o_ref.dtype)
         # state update: S ← diag(exp(c_τ)) S + Σ_s (k_s ⊙ exp(c_τ - c_s)) v_s^T
-        c_tau = c[-1]
-        k_dec = ks * jnp.exp(c_tau[None, :] - c)
-        s_out = jnp.exp(c_tau)[:, None] * s_in + jax.lax.dot(k_dec.T, vs)
-        return (s_out, o_acc)
+        c_tau = c[sub - 1:sub, :]                     # (1, D)
+        k_dec = ks * jnp.exp(c_tau - c)
+        decay = jnp.where(eye, jnp.exp(c_tau), 0.0)   # diag(exp(c_τ))
+        return jax.lax.dot(decay, s_in) + jax.lax.dot_general(
+            k_dec, vs, (((0,), (0,)), ((), ())), preferred_element_type=f32)
 
-    s_in = s_ref[...]
-    o_init = jnp.zeros((chunk, d), jnp.float32)
-    s_out, o = jax.lax.fori_loop(0, nsub, sub_step, (s_in, o_init))
-    s_ref[...] = s_out
-    o_ref[0] = o.astype(o_ref.dtype)
+    s_ref[...] = jax.lax.fori_loop(0, chunk // sub, sub_step, s_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "sub", "interpret"))
@@ -116,10 +113,11 @@ def wkv6_pallas(
             pl.BlockSpec((1, chunk, d), blk),
             pl.BlockSpec((1, chunk, d), blk),
             pl.BlockSpec((1, chunk, d), blk),
-            pl.BlockSpec((1, d), lambda b, i: (b, 0)),
+            # u as [BH, 1, D]: the block's last two dims are full extents
+            pl.BlockSpec((1, 1, d), lambda b, i: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, d), blk),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, lw, u)
+    )(q, k, v, lw, u[:, None, :])

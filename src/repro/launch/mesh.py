@@ -10,13 +10,37 @@ Multi-host: every launcher (train/serve) takes ``--coordinator``,
 ``jax.distributed.initialize`` before any other jax API so each process
 sees the global device set.  The single-host default is a strict no-op —
 nothing about the existing entry points changes.
+
+Mesh axes are ``Auto``: GSPMD propagates shardings from the
+``with_sharding_constraint`` hints the runtime places (DESIGN.md §3).
 """
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh",
-           "add_distributed_cli_args", "maybe_initialize_distributed"]
+           "add_distributed_cli_args", "maybe_initialize_distributed",
+           "enable_compile_cache", "COMPILE_CACHE_DIR"]
+
+# Fixed, so a later run of this checkout finds what an earlier one cached
+# (the directory is part of the cache key).
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own reading
+    of it stands and nothing is set here; otherwise the cache goes to
+    ``<checkout>/.jax_cache``.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def add_distributed_cli_args(ap) -> None:
@@ -73,10 +97,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     inter-node links."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over host platform devices (tests / examples).  Requires
     the caller to have set --xla_force_host_platform_device_count."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

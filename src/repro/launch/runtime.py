@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .. import sharding as sh
 from ..configs.base import ArchConfig, InputShape
@@ -44,10 +44,11 @@ from ..engine import (ConfigError, MicroEPEngine, PlacementSpec,
 from ..models import decoder as dec
 from ..moe.layer import MoEMetrics, moe_ffn
 from ..moe.router import top_k_gating
-from ..optim.adamw import AdamWConfig
+from ..optim.adamw import AdamWConfig, AdamWState, adamw_init
 from ..train.loop import LayoutHooks, TrainState, make_train_step
 
-__all__ = ["DistRuntime", "build_runtime", "make_placement", "input_specs"]
+__all__ = ["DistRuntime", "build_runtime", "make_placement", "input_specs",
+           "init_master"]
 
 
 def make_placement(cfg: ArchConfig, mi: sh.MeshInfo,
@@ -89,15 +90,32 @@ class DistRuntime:
 
     # ---------------- abstract shapes for lowering ----------------------
     def master_sds(self):
-        shapes = jax.eval_shape(
-            lambda k: dec.init_params(k, self.cfg, jnp.float32,
-                                      layout=self.layout),
-            jax.random.PRNGKey(0))
-        specs = sh.master_pspecs(shapes, self.mi, self.cfg)
-        return jax.tree_util.tree_map(
-            lambda s, sp: jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                               sharding=self.mi.named(sp)),
-            shapes, specs)
+        return _master_sds(self.cfg, self.mi, self.layout)
+
+    def train_state_shardings(self) -> TrainState:
+        """Where the train state lives: the master (and Adam's moments) in
+        the ``sh.master_pspecs`` layout, step and warm start replicated."""
+        m_sh = jax.tree_util.tree_map(lambda s: s.sharding, self.master_sds())
+        rep = self.mi.named(P())
+        solver_sds = self.solver_sds()
+        return TrainState(
+            master=m_sh, opt=AdamWState(step=rep, mu=m_sh, nu=m_sh),
+            solver=None if solver_sds is None else jax.tree_util.tree_map(
+                lambda s: s.sharding, solver_sds),
+            step=rep)
+
+    def new_train_state(self, key) -> TrainState:
+        master = dec.init_params(key, self.cfg, jnp.float32,
+                                 layout=self.layout)
+        return TrainState(master=master, opt=adamw_init(master),
+                          solver=self.init_solver(),
+                          step=jnp.zeros((), jnp.int32))
+
+    def init_train_state(self, key) -> TrainState:
+        """The train state built where it lives (jit ``out_shardings``), so
+        no device ever holds the whole tree first."""
+        return jax.jit(self.new_train_state,
+                       out_shardings=self.train_state_shardings())(key)
 
     def params_sds(self):
         master = self.master_sds()
@@ -126,6 +144,27 @@ class DistRuntime:
         e = self.cfg.num_experts * max(self.cfg.etp, 1)
         r = self.sched_statics.max_replicas if self.cfg.moe else 1
         return _init_solver(self.cfg, self.mi.pods, e, r, self.layout)
+
+
+def _master_sds(cfg: ArchConfig, mi: sh.MeshInfo, layout: str):
+    shapes = jax.eval_shape(
+        lambda k: dec.init_params(k, cfg, jnp.float32, layout=layout),
+        jax.random.PRNGKey(0))
+    specs = sh.master_pspecs(shapes, mi, cfg)
+    return jax.tree_util.tree_map(
+        lambda s, sp: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=mi.named(sp)),
+        shapes, specs)
+
+
+def init_master(cfg: ArchConfig, mesh: Mesh, key, layout: str = "scan"):
+    """The f32 master built where it lives (``sh.master_pspecs``) rather
+    than on the default device."""
+    out = jax.tree_util.tree_map(
+        lambda s: s.sharding, _master_sds(cfg, sh.MeshInfo(mesh), layout))
+    return jax.jit(lambda k: dec.init_params(k, cfg, jnp.float32,
+                                             layout=layout),
+                   out_shardings=out)(key)
 
 
 def _init_solver(cfg: ArchConfig, pods: int, e_virt: int, r: int,
@@ -213,7 +252,7 @@ def _build_moe_apply(cfg: ArchConfig, mi: sh.MeshInfo,
             inner, mesh=mi.mesh,
             in_specs=(P(), P("data", "model"), tok_spec, pod_spec, tok_spec),
             out_specs=(tok_spec, P(), pod_spec),
-            check_rep=False,
+            check_vma=False,
         )(p_moe["router"], p_moe["experts"], x2d, state, valid)
         return out[:n], metrics, new_state
 
@@ -253,7 +292,9 @@ def _build_hooks(cfg: ArchConfig, mi: sh.MeshInfo,
         return jax.tree_util.tree_unflatten(
             treedef, [leaf(p, x) for p, x in flat])
 
-    return LayoutHooks(to_working=to_working)
+    # compiled, so that called outside a step (serving) the gather and
+    # cast run as one program instead of one device copy per op
+    return LayoutHooks(to_working=jax.jit(to_working))
 
 
 # --------------------------------------------------------------------------

@@ -45,6 +45,7 @@ local mesh through the distributed runtime.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 
 from ..configs import get_config
@@ -53,14 +54,17 @@ from ..engine import (DisaggConfig, FleetConfig, ReplicationConfig,
                       TelemetryConfig)
 from ..serve import (ServingSession, load_trace, poisson_trace, replay_trace,
                      trace_requests)
-from .mesh import (add_distributed_cli_args, make_local_mesh,
-                   maybe_initialize_distributed)
+from .mesh import (add_distributed_cli_args, enable_compile_cache,
+                   make_local_mesh, maybe_initialize_distributed)
 
 
-def main(argv=None):
+def run(argv=None):
+    """Serve the trace and return ``(ServeReport, requests)``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="override num_layers only; every width stays")
     ap.add_argument("--traffic", default="poisson",
                     choices=["poisson", "replay", "trace"],
                     help="'trace' shapes non-stationary arrivals from a "
@@ -84,7 +88,7 @@ def main(argv=None):
                     help="print the full ServeReport as JSON")
     # shared engine + serving flag surfaces (same parser family as train)
     RuntimeConfig.add_cli_args(
-        ap, defaults=RuntimeConfig(dtype="float32", impl="ref", remat=False))
+        ap, defaults=RuntimeConfig(dtype="float32", remat=False))
     ServeConfig.add_cli_args(ap)
     TelemetryConfig.add_cli_args(ap)
     ReplicationConfig.add_cli_args(ap)
@@ -123,6 +127,10 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    print(f"serve arch={cfg.name} layers={cfg.num_layers} "
+          f"impl={run_cfg.impl or 'default'} dtype={run_cfg.dtype}")
     # convenience: grow the default cache to fit the requested lengths, but
     # never override explicit --max-seq / --kv-budget (oversize requests
     # are then rejected and reported instead)
@@ -186,8 +194,14 @@ def main(argv=None):
               f"{telemetry.trace_path}")
     if args.json:
         print(json.dumps(report.to_dict(), indent=1))
+    return report, requests
+
+
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

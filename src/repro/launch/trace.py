@@ -48,7 +48,7 @@ def _record(args) -> int:
                                 max_seq=args.prompt_len + args.gen)
         sess = ServingSession(
             cfg, serve_cfg,
-            run_cfg=RuntimeConfig(dtype="float32", impl="ref", remat=False),
+            run_cfg=RuntimeConfig(dtype="float32", remat=False),
             seed=args.seed, telemetry=telemetry)
         requests = poisson_trace(args.requests, args.rate, cfg.vocab,
                                  prompt_len=args.prompt_len,

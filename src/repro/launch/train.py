@@ -19,6 +19,8 @@ default is a no-op.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -37,15 +39,21 @@ from ..telemetry import (LoadTraceRecorder, ReplacementPlanner,
 from ..train.loop import TrainState, make_train_step
 from ..train.metrics import MetricLogger
 from . import runtime as R
-from .mesh import (add_distributed_cli_args, make_local_mesh,
-                   make_production_mesh, maybe_initialize_distributed)
+from .mesh import (add_distributed_cli_args, enable_compile_cache,
+                   make_local_mesh, make_production_mesh,
+                   maybe_initialize_distributed)
 
 
-def main(argv=None):
+def run(argv=None) -> dict:
+    """Train and return the run's record: per-step metric rows
+    (``history``), compile seconds, per-step seconds, and whether the
+    compiled step holds a Pallas kernel (``tpu_custom_call``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced same-family config")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="override num_layers only; every width stays")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--seq", type=int, default=64)
@@ -61,7 +69,7 @@ def main(argv=None):
     # shared engine flag surface (same parser as serve/bench): CPU-scale
     # training defaults to float32 master math without remat
     RuntimeConfig.add_cli_args(
-        ap, defaults=RuntimeConfig(dtype="float32", impl="ref", remat=False))
+        ap, defaults=RuntimeConfig(dtype="float32", remat=False))
     TelemetryConfig.add_cli_args(ap)
     ReplicationConfig.add_cli_args(ap)
     add_distributed_cli_args(ap)
@@ -78,6 +86,10 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    print(f"train arch={cfg.name} layers={cfg.num_layers} "
+          f"impl={run_cfg.impl or 'default'} dtype={run_cfg.dtype}")
     # telemetry needs the per-step expert-load vector out of the compiled
     # step (TELEMETRY.md); dense configs have nothing to record
     want_load = cfg.moe and (telemetry.record or telemetry.prewarm
@@ -92,13 +104,14 @@ def main(argv=None):
         mesh = (make_production_mesh() if args.production_mesh
                 else make_local_mesh(args.data_axis, args.model_axis))
         dr = R.build_runtime(cfg, mesh, run_cfg)
-        master = dec.init_params(key, cfg, jnp.float32)
-        ts = TrainState(master=master, opt=adamw_init(master),
-                        solver=dr.init_solver() if cfg.moe else None,
-                        step=jnp.zeros((), jnp.int32))
-        step = jax.jit(R.make_train_fn(dr, n_micro=args.n_micro,
-                                       opt_cfg=opt_cfg,
-                                       with_expert_load=want_load))
+        ts, ts_sh = dr.init_train_state(key), dr.train_state_shardings()
+
+        def jit_step(dr):
+            return jax.jit(R.make_train_fn(dr, n_micro=args.n_micro,
+                                           opt_cfg=opt_cfg,
+                                           with_expert_load=want_load),
+                           out_shardings=(ts_sh, None), donate_argnums=0)
+        step = jit_step(dr)
         placement = dr.engine.placement if cfg.moe else None
     else:
         dr = None
@@ -106,9 +119,11 @@ def main(argv=None):
         ts = TrainState(master=master, opt=adamw_init(master),
                         solver=dec.init_solver_states(cfg, 1),
                         step=jnp.zeros((), jnp.int32))
-        step = jax.jit(make_train_step(cfg, opt_cfg=opt_cfg,
+        step = jax.jit(make_train_step(cfg, dec.Runtime(impl=run_cfg.impl),
+                                       opt_cfg=opt_cfg,
                                        n_micro=args.n_micro, lr_fn=lr_fn,
-                                       with_expert_load=want_load))
+                                       with_expert_load=want_load),
+                       donate_argnums=0)
         placement = None
         if cfg.moe:
             from ..core.placement import vanilla_placement
@@ -156,8 +171,18 @@ def main(argv=None):
     data = SyntheticLM(vocab=cfg.vocab, seq_len=args.seq, batch=args.batch,
                        noise=0.05, n_maps=4, seed=args.seed + 1)
     logger = MetricLogger(csv_path=args.csv, print_every=10)
+    compiled, compile_s, step_s, has_kernel = None, 0.0, [], False
     for i, batch in zip(range(args.steps), data):
-        ts, m = step(ts, batch)
+        if compiled is None:
+            t0 = time.perf_counter()
+            compiled = step.lower(ts, batch).compile()
+            compile_s += time.perf_counter() - t0
+            has_kernel = has_kernel or \
+                "tpu_custom_call" in compiled.as_text()
+        t0 = time.perf_counter()
+        ts, m = compiled(ts, batch)
+        jax.block_until_ready(m)
+        step_s.append(time.perf_counter() - t0)
         if want_load:
             eload = np.asarray(m.pop("expert_load"), np.float64)
             if recorder is not None:
@@ -170,10 +195,9 @@ def main(argv=None):
                     # migration; the re-jit suspension is the cost)
                     dr = R.build_runtime(cfg, mesh, run_cfg,
                                          placement_table=new_table)
-                    step = jax.jit(R.make_train_fn(
-                        dr, n_micro=args.n_micro, opt_cfg=opt_cfg,
-                        with_expert_load=want_load))
-                    ts = ts._replace(solver=dr.init_solver())
+                    step, compiled = jit_step(dr), None
+                    ts = ts._replace(solver=jax.device_put(
+                        dr.init_solver(), ts_sh.solver))
                     placement = dr.engine.placement
                     if planner is not None:
                         planner.placement = placement
@@ -203,8 +227,16 @@ def main(argv=None):
     last = logger.history[-1]["loss"]
     print(f"loss {first:.4f} -> {last:.4f} "
           f"({'improved' if last < first else 'NO IMPROVEMENT'})")
+    return {"arch": cfg.name, "layers": cfg.num_layers,
+            "history": logger.history, "compile_s": compile_s,
+            "step_s": step_s, "has_pallas_kernel": has_kernel}
+
+
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

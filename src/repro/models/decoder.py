@@ -260,7 +260,7 @@ def _local_moe_spec(num_virtual: int, top_k_eff: int, tokens: int,
                     activation: str, impl: Optional[str]) -> MoEFFNSpec:
     return _local_moe_engine(num_virtual).moe_spec(
         tokens, top_k_eff, activation=activation, group_axes=(),
-        capacity_factor=2.0, bm=8, kernel_impl=impl or "ref")
+        capacity_factor=2.0, bm=8, kernel_impl=impl)
 
 
 def local_moe_apply(p_moe, x2d, cfg: ArchConfig, state, impl=None,

@@ -58,11 +58,12 @@ def expert_ffn_flat(
     params: ExpertParams,     # local slots [S, H, F] etc.
     activation: str,
     impl: str | None = None,
+    bm: int = 128,
 ) -> jax.Array:
     return ops.grouped_ffn_flat(
         flat, group_start, group_end,
         params.w_gate, params.w_up, params.w_down,
-        activation=activation, impl=impl,
+        activation=activation, impl=impl, bm=bm,
     )
 
 
@@ -73,11 +74,12 @@ def expert_ffn_flat_chunked(
     params: ExpertParams,
     activation: str,
     impl: str | None = None,
+    bm: int = 128,
 ):
     """Pipelined variant: one grouped-FFN call per dispatch chunk, weights
     padded once (kernels.ops.grouped_ffn_flat_chunked)."""
     return ops.grouped_ffn_flat_chunked(
         flat_chunks, group_starts, group_ends,
         params.w_gate, params.w_up, params.w_down,
-        activation=activation, impl=impl,
+        activation=activation, impl=impl, bm=bm,
     )

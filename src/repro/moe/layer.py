@@ -140,7 +140,7 @@ def moe_ffn(
             chunk_comm=spec.chunk_comm)
         out_chunks = expert_ffn_flat_chunked(
             flat_chunks, plan.group_start, plan.group_end, experts,
-            spec.activation, impl=spec.kernel_impl,
+            spec.activation, impl=spec.kernel_impl, bm=st.bm,
         )
         if spec.tp_axis is not None:
             out_chunks = tuple(jax.lax.psum(o, spec.tp_axis)
@@ -154,7 +154,7 @@ def moe_ffn(
                           mode=spec.dispatch_mode)
         out_flat = expert_ffn_flat(
             flat, plan.group_start, plan.group_end, experts,
-            spec.activation, impl=spec.kernel_impl,
+            spec.activation, impl=spec.kernel_impl, bm=st.bm,
         )
         if spec.tp_axis is not None:
             out_flat = jax.lax.psum(out_flat, spec.tp_axis)

@@ -266,7 +266,7 @@ class ServingSession:
             self.serve_cfg = serve_cfg = dataclasses.replace(
                 serve_cfg, max_batch=width)
         self.run_cfg = run_cfg if run_cfg is not None else RuntimeConfig(
-            dtype="float32", impl="ref", remat=False)
+            dtype="float32", remat=False)
         self.mesh = mesh
         self.n_moe = dec.n_moe_layers(cfg)
         key = jax.random.PRNGKey(seed)
@@ -274,9 +274,9 @@ class ServingSession:
         if mesh is not None:
             from ..launch import runtime as R     # avoid cycle at import
             self._R = R
+            self.master = R.init_master(cfg, mesh, key)
             if self.disagg is None:
                 self.dr = R.build_runtime(cfg, mesh, self.run_cfg)
-                self.master = dec.init_params(key, cfg, jnp.float32)
                 self.params = self.dr.hooks.to_working(self.master)
                 self.rt = self.dr.rt
                 self.dtype = self.dr.dtype
@@ -285,7 +285,6 @@ class ServingSession:
                 # its own profile mix (_build_fleet); the session keeps
                 # only the canonical master both fleets materialize from
                 self.dr = None
-                self.master = dec.init_params(key, cfg, jnp.float32)
                 self.params = None
                 self.rt = None
                 self.dtype = jnp.float32

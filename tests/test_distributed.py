@@ -134,7 +134,7 @@ def test_edp_grad_sync_ppermute_matches_scatter():
     run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core.placement import latin_placement
 from repro.moe.sync import (build_sync_plan, working_grads_to_canonical,
                             canonical_to_working)
@@ -172,7 +172,7 @@ canon_out, work_out = shard_map(per_device, mesh=mesh,
               P(None, ("data", "model"), None),
               P(("data", "model"), None, None)),
     out_specs=(P("data", "model"), P("data", "model")),
-    check_rep=False)(jnp.asarray(g_work), send, recv, own)
+    check_vma=False)(jnp.asarray(g_work), send, recv, own)
 
 canon_out = np.asarray(canon_out)   # [D, M, k, 3, 5]
 for d in range(2):
@@ -198,7 +198,7 @@ def test_seq_sharded_flash_decode_matches_local():
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.models.layers.attention import (AttnConfig, init_attention,
                                            decode_attention, init_kv_cache,
                                            attention)
@@ -228,7 +228,7 @@ def step(p, x_t, k_all, v_all, length):
         in_specs=(P(), P(), P(None, None, "data", None),
                   P(None, None, "data", None), P()),
         out_specs=(P(), P(None, None, "data", None),
-                   P(None, None, "data", None)), check_rep=False)(
+                   P(None, None, "data", None)), check_vma=False)(
         p, x_t, k_all, v_all, length)
 
 outs = []

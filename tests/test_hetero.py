@@ -334,7 +334,7 @@ _MESH_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.engine import MicroEPEngine, PlacementSpec, SchedulePolicy
 from repro.launch.mesh import make_local_mesh
 from repro.moe.experts import init_canonical_experts, ExpertParams
@@ -370,7 +370,7 @@ def run(eng, stages, comm="ppermute", mode="packed"):
         inner, mesh=mesh,
         in_specs=(P(), P("data", "model"), P(("data", "model"))),
         out_specs=(P(("data", "model")),) * 3,
-        check_rep=False)(w_router, work, x)
+        check_vma=False)(w_router, work, x)
     return np.asarray(out), np.asarray(ovf), np.asarray(bal)
 
 

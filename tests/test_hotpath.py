@@ -249,7 +249,7 @@ def test_chunk_caps_accounting():
 _MESH_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.engine import MicroEPEngine
 from repro.launch.mesh import make_local_mesh
 from repro.moe.experts import init_canonical_experts, ExpertParams
@@ -286,7 +286,7 @@ for rows, cols in [(1, 1), (1, 2), (2, 2)]:
             inner, mesh=mesh,
             in_specs=(P(), P("data", "model"), P(("data", "model"))),
             out_specs=(P(("data", "model")), P(("data", "model"))),
-            check_rep=False)(w_router, work, x)
+            check_vma=False)(w_router, work, x)
         return np.asarray(out), np.asarray(ovf)
 
     base, ovf = run(1, mode="scatter")

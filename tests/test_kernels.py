@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.grouped_matmul import grouped_ffn_pallas
 from repro.kernels.wkv6_chunk import wkv6_pallas
 
 
@@ -74,6 +73,40 @@ def test_grouped_ffn_flat_vs_ref(dtype):
     expect = ref.grouped_ffn_flat_ref(x, group_start, group_end, wg, wu, wd)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("activation", ["swiglu", "geglu"])
+def test_grouped_ffn_flat_custom_vjp_matches_ref_grad(activation):
+    """The Pallas path's custom VJP (ragged-dot backward) gives the
+    gradients of the oracle, junk rows outside [start, end) included."""
+    bm, s, h, f = 128, 3, 128, 256
+    counts = jnp.asarray([100, 0, 250], jnp.int32)
+    sizes_pad = ((counts + bm - 1) // bm) * bm
+    group_start = jnp.cumsum(sizes_pad) - sizes_pad
+    group_end = group_start + counts
+    n = int(sizes_pad.sum()) + bm            # one trailing tile of junk
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(ks[0], (n, h)) * 0.5
+    rows = jnp.arange(n)[:, None]
+    member = jnp.any((rows >= group_start) & (rows < group_end), axis=1)
+    x = jnp.where(member[:, None], x, 1e3)   # junk must not leak
+    wg = jax.random.normal(ks[1], (s, h, f)) * h ** -0.5
+    wu = jax.random.normal(ks[2], (s, h, f)) * h ** -0.5
+    wd = jax.random.normal(ks[3], (s, f, h)) * f ** -0.5
+    probe = jax.random.normal(ks[4], (n, h))
+
+    def loss(impl):
+        def fn(x_, wg_, wu_, wd_):
+            out = ops.grouped_ffn_flat(x_, group_start, group_end, wg_, wu_,
+                                       wd_, activation=activation, impl=impl)
+            return jnp.sum(out * probe)
+        return fn
+
+    got = jax.grad(loss("interpret"), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    want = jax.grad(loss("ref"), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
 
 
 def test_grouped_ffn_flat_ref_vs_grouped_ref():

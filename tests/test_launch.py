@@ -139,3 +139,55 @@ def test_sharding_policy_rules():
     # embedding vocab-sharded
     spec = sh.param_pspec("embed", (262144, 5376), mi, None, scanned=False)
     assert spec == P("model", None)
+
+
+def test_train_layers_cuts_depth_only():
+    """``--layers`` overrides num_layers and nothing else; ``run`` returns
+    the per-step record the chip smoke checks."""
+    from repro.launch import train
+    res = train.run(["--arch", "olmoe-1b-7b", "--smoke", "--layers", "1",
+                     "--steps", "2", "--batch", "2", "--seq", "16",
+                     "--impl", "ref"])
+    assert res["layers"] == 1 and len(res["history"]) == 2
+    assert len(res["step_s"]) == 2 and res["compile_s"] > 0
+    assert all(np.isfinite(r["loss"]) and r["overflow"] == 0
+               for r in res["history"])
+    assert not res["has_pallas_kernel"]       # impl=ref: no Mosaic call
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.launch.mesh import COMPILE_CACHE_DIR, enable_compile_cache
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+used = enable_compile_cache()
+assert jax.config.jax_compilation_cache_dir == used, used
+print("USED", used, COMPILE_CACHE_DIR)
+if used != str(COMPILE_CACHE_DIR):
+    jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+"""
+
+
+def test_compile_cache_placement(tmp_path):
+    """The env var, where set, decides the cache directory and the cache is
+    written there; otherwise it is the fixed in-checkout path."""
+    import os
+    import subprocess
+    import sys
+    from repro.launch.mesh import COMPILE_CACHE_DIR
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(COMPILE_CACHE_DIR.parent / "src"), env.get("PYTHONPATH", "")])
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert f"USED {COMPILE_CACHE_DIR} " in r.stdout
+    checkout = COMPILE_CACHE_DIR.parent          # fixed, and gitignored
+    assert (checkout / "src" / "repro").is_dir()
+    assert ".jax_cache/" in (checkout / ".gitignore").read_text().split()
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert f"USED {tmp_path} " in r.stdout
+    assert any(tmp_path.iterdir()), "nothing cached in JAX_COMPILATION_CACHE_DIR"
