@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import scopes
 from . import lp as lp_host
 from .placement import Placement
 from .rounding import round_replica_loads
@@ -33,6 +34,9 @@ from .solver_jax import (SolverState, device_loads, solve_replica_loads,
                          solve_replica_loads_batched)
 
 __all__ = ["ScheduleStatics", "Schedule", "MicroEPScheduler"]
+
+# Eq. 3 enumerates the 2^G - 1 device subsets; larger groups get no bound
+LP_BOUND_MAX_DEVICES = 12
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,6 +117,31 @@ class Schedule(NamedTuple):
     solver_state: SolverState  # warm-start carry for the next micro-batch
     max_load: jax.Array      # f32[] resulting max device load (diagnostic)
     balance: jax.Array       # f32[] max/mean device load (diagnostic)
+    lp_bound: jax.Array      # f32[] the LP optimum (Eq. 3): the least
+                             # max_g L_g / w_g any split reaches (w mean-
+                             # normalized; tokens on a uniform group); NaN
+                             # above LP_BOUND_MAX_DEVICES devices
+    balance_gap: jax.Array   # f32[] achieved max_g L_g / w_g over lp_bound
+                             # (>= 1; 0 when nothing is routed)
+
+
+def _subset_density(statics: ScheduleStatics) -> Optional[np.ndarray]:
+    """f32[2^G - 1, E]: row S holds 1 / w(S) at each expert whose replicas
+    all lie in device subset S (bit g of S+1 set = device g in S), else 0.
+    Its product with the expert loads, maximized over S, is Eq. 3's LP
+    optimum, as ``core.placement.max_induced_density`` enumerates it
+    (MemFine caps are not part of it).  None above LP_BOUND_MAX_DEVICES."""
+    g = statics.num_devices
+    if g > LP_BOUND_MAX_DEVICES:
+        return None
+    dev = np.asarray(statics.dev)
+    bits = np.bitwise_or.reduce(
+        np.where(dev >= 0, 1 << np.maximum(dev, 0), 0), axis=1)
+    subsets = np.arange(1, 1 << g, dtype=np.int64)
+    inside = (bits[None, :] & ~subsets[:, None]) == 0
+    member = (subsets[:, None] >> np.arange(g)) & 1
+    w = np.ones(g) if statics.weights is None else statics.weights
+    return (inside / (member @ w)[:, None]).astype(np.float32)
 
 
 class MicroEPScheduler:
@@ -167,11 +196,13 @@ class MicroEPScheduler:
         # MemFine token caps (DESIGN.md §16): None = memory-oblivious path
         self._mem_caps = (None if statics.mem_caps is None
                           else np.asarray(statics.mem_caps, np.float32))
+        self._density = _subset_density(statics)
 
     def init_state(self) -> SolverState:
         e, r = self.statics.dev.shape
         return SolverState(x=jnp.zeros((e, r), jnp.float32))
 
+    @jax.named_scope(scopes.ALG1)
     def __call__(
         self, input_eg: jax.Array, state: Optional[SolverState] = None,
         mem_caps: Optional[jax.Array] = None,
@@ -208,29 +239,31 @@ class MicroEPScheduler:
             dl = device_loads(x_int.astype(jnp.float32), dev, st.num_devices)
             state_out = state if state is not None else self.init_state()
         else:
-            if self.solver_mode == "batched":
-                # a damped-Jacobi sweep makes roughly half the progress of
-                # a Gauss-Seidel sweep but costs one vectorized step, so 2x
-                # the sweeps still cuts the sequential-depth bottleneck
-                sol = solve_replica_loads_batched(
-                    loads.astype(jnp.float32),
-                    dev,
-                    st.num_devices,
-                    x_init=None if state is None else state.x,
-                    sweeps=2 * self.sweeps,
-                    weights=weights,
-                    mem_caps=caps,
-                )
-            else:
-                sol = solve_replica_loads(
-                    loads.astype(jnp.float32),
-                    dev,
-                    st.num_devices,
-                    x_init=None if state is None else state.x,
-                    sweeps=self.sweeps,
-                    weights=weights,
-                    mem_caps=caps,
-                )
+            with jax.named_scope(scopes.LP_SOLVE):
+                if self.solver_mode == "batched":
+                    # a damped-Jacobi sweep makes roughly half the progress
+                    # of a Gauss-Seidel sweep but costs one vectorized step,
+                    # so 2x the sweeps still cuts the sequential-depth
+                    # bottleneck
+                    sol = solve_replica_loads_batched(
+                        loads.astype(jnp.float32),
+                        dev,
+                        st.num_devices,
+                        x_init=None if state is None else state.x,
+                        sweeps=2 * self.sweeps,
+                        weights=weights,
+                        mem_caps=caps,
+                    )
+                else:
+                    sol = solve_replica_loads(
+                        loads.astype(jnp.float32),
+                        dev,
+                        st.num_devices,
+                        x_init=None if state is None else state.x,
+                        sweeps=self.sweeps,
+                        weights=weights,
+                        mem_caps=caps,
+                    )
             x_int = round_replica_loads(sol.x, loads, valid)
             routed = route_tokens(input_eg, x_int, dev,
                                   locality=self.locality,
@@ -244,12 +277,19 @@ class MicroEPScheduler:
         # ideal per-unit-weight load is still the plain mean)
         mean = jnp.maximum(dl.mean(), 1e-9)
         dl_norm = dl if weights is None else dl / weights
+        if self._density is None:
+            lp_bound = jnp.full((), jnp.nan, jnp.float32)
+        else:
+            lp_bound = jnp.max(jnp.sum(jnp.asarray(self._density)
+                                       * loads.astype(jnp.float32), axis=1))
         return Schedule(
             flow=flow,
             x_int=x_int,
             solver_state=state_out,
             max_load=dl.max(),
             balance=dl_norm.max() / mean,
+            lp_bound=lp_bound,
+            balance_gap=dl_norm.max() / jnp.maximum(lp_bound, 1e-9),
         )
 
     # ---------------- host-side oracle (paper's HiGHS path) ----------------

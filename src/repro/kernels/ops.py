@@ -13,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import scopes
 from . import ref
 from .grouped_matmul import grouped_ffn_flat_pallas
 from .wkv6_chunk import wkv6_pallas
@@ -152,7 +153,7 @@ def _flat_padded_fwd(x, group_start, group_end, wgp, wup, wdp,
 
 def _flat_padded_bwd(activation, bm, bf, interpret, res, g):
     x, group_start, group_end, wgp, wup, wdp = res
-    with jax.named_scope("grouped_ffn_bwd"):
+    with jax.named_scope(scopes.GROUPED_FFN_BWD):
         sizes, valid = _row_groups(x.shape[0], group_start, group_end)
         _, vjp = jax.vjp(
             lambda x_, wg, wu, wd: _ragged_ffn(x_, sizes, valid, wg, wu, wd,

@@ -25,6 +25,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..checkpoint import save_checkpoint
 from ..configs import get_config
@@ -172,42 +173,50 @@ def run(argv=None) -> dict:
                        noise=0.05, n_maps=4, seed=args.seed + 1)
     logger = MetricLogger(csv_path=args.csv, print_every=10)
     compiled, compile_s, step_s, has_kernel = None, 0.0, [], False
-    for i, batch in zip(range(args.steps), data):
-        if compiled is None:
+    # host spans on the profiler's clock (TELEMETRY.md): a trace of this
+    # loop names each step and what the host did between the steps
+    batches = iter(data)
+    for i in range(args.steps):
+        with TraceAnnotation("repro.feed"):
+            batch = next(batches)
+        with StepTraceAnnotation("train", step_num=i):
+            if compiled is None:
+                t0 = time.perf_counter()
+                compiled = step.lower(ts, batch).compile()
+                compile_s += time.perf_counter() - t0
+                has_kernel = has_kernel or \
+                    "tpu_custom_call" in compiled.as_text()
             t0 = time.perf_counter()
-            compiled = step.lower(ts, batch).compile()
-            compile_s += time.perf_counter() - t0
-            has_kernel = has_kernel or \
-                "tpu_custom_call" in compiled.as_text()
-        t0 = time.perf_counter()
-        ts, m = compiled(ts, batch)
-        jax.block_until_ready(m)
+            ts, m = compiled(ts, batch)
+        with TraceAnnotation("repro.fetch"):
+            jax.block_until_ready(m)
         step_s.append(time.perf_counter() - t0)
         if want_load:
-            eload = np.asarray(m.pop("expert_load"), np.float64)
-            if recorder is not None:
-                recorder.record(i, eload)
-            if controller is not None:
-                new_table = controller.observe(eload)
-                if new_table is not None and dr is not None:
-                    # topology migration: rebuild the runtime around the
-                    # new table (PR 2 machinery — same path as a serving
-                    # migration; the re-jit suspension is the cost)
-                    dr = R.build_runtime(cfg, mesh, run_cfg,
-                                         placement_table=new_table)
-                    step, compiled = jit_step(dr), None
-                    ts = ts._replace(solver=jax.device_put(
-                        dr.init_solver(), ts_sh.solver))
-                    placement = dr.engine.placement
-                    if planner is not None:
-                        planner.placement = placement
-            if planner is not None:
-                planner.observe(eload)
-                if planner.history_size >= planner.min_history:
-                    # in-graph batched solver: no per-step host LP
-                    ts = ts._replace(solver=prewarm_solver_states(
-                        ts.solver,
-                        planner.warm_start_x(solver="jacobi")))
+            with TraceAnnotation("repro.telemetry"):
+                eload = np.asarray(m.pop("expert_load"), np.float64)
+                if recorder is not None:
+                    recorder.record(i, eload)
+                if controller is not None:
+                    new_table = controller.observe(eload)
+                    if new_table is not None and dr is not None:
+                        # topology migration: rebuild the runtime around
+                        # the new table (the same path as a serving
+                        # migration; the re-jit suspension is the cost)
+                        dr = R.build_runtime(cfg, mesh, run_cfg,
+                                             placement_table=new_table)
+                        step, compiled = jit_step(dr), None
+                        ts = ts._replace(solver=jax.device_put(
+                            dr.init_solver(), ts_sh.solver))
+                        placement = dr.engine.placement
+                        if planner is not None:
+                            planner.placement = placement
+                if planner is not None:
+                    planner.observe(eload)
+                    if planner.history_size >= planner.min_history:
+                        # in-graph batched solver: no per-step host LP
+                        ts = ts._replace(solver=prewarm_solver_states(
+                            ts.solver,
+                            planner.warm_start_x(solver="jacobi")))
         logger.log(i, m)
     logger.close()
     if controller is not None and controller.replacements:

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import scopes
 from ..configs.base import ArchConfig
 from ..core.solver_jax import SolverState
 from ..engine import MicroEPEngine
@@ -94,6 +95,7 @@ class Metrics(NamedTuple):
     z_loss: jax.Array
     balance: jax.Array    # mean over MoE layers of max/mean device load
     overflow: jax.Array   # total capacity-overflow rows (0 in practice)
+    balance_gap: jax.Array  # mean over MoE layers of max load / LP optimum
 
 
 # --------------------------------------------------------------------------
@@ -233,6 +235,7 @@ def param_dtypes(params, dtype):
 # --------------------------------------------------------------------------
 
 
+@jax.named_scope(scopes.ROUTER)
 def expand_router_etp(r, etp: int):
     """Virtual-expert expansion for intra-expert tensor parallelism.
 
@@ -324,16 +327,19 @@ def _block_fwd(p, cfg: ArchConfig, rt: Runtime, kind: str,
     metrics = _ZERO_MOE
     new_state = state
     if kind.startswith("attn"):
-        h = _norm(cfg, p["ln1"], x)
-        h = attention(p["attn"], _attn_cfg(cfg, kind), h, positions,
-                      unroll=rt.unroll)
-        x = x + h
-        h = _norm(cfg, p["ln2"], x)
-        if cfg.moe:
-            h, metrics, new_state = _moe_block(p["moe"], h, cfg, rt, state)
-        else:
-            h = ffn(p["ffn"], h, cfg.ffn_kind)
-        x = x + h
+        with jax.named_scope(scopes.ATTENTION):
+            h = _norm(cfg, p["ln1"], x)
+            h = attention(p["attn"], _attn_cfg(cfg, kind), h, positions,
+                          unroll=rt.unroll)
+            x = x + h
+        with jax.named_scope(scopes.MOE if cfg.moe else scopes.FFN):
+            h = _norm(cfg, p["ln2"], x)
+            if cfg.moe:
+                h, metrics, new_state = _moe_block(p["moe"], h, cfg, rt,
+                                                   state)
+            else:
+                h = ffn(p["ffn"], h, cfg.ffn_kind)
+            x = x + h
     elif kind == "rwkv":
         h = _norm(cfg, p["ln1"], x)
         h, _, _ = rwkv6_time_mix(p["time"], h, cfg.num_heads, impl=rt.impl)
@@ -356,7 +362,8 @@ def _block_fwd(p, cfg: ArchConfig, rt: Runtime, kind: str,
 
 def _accum(acc, m: MoEMetrics):
     return MoEMetrics(acc.aux_loss + m.aux_loss, acc.z_loss + m.z_loss,
-                      acc.max_load + m.max_load, acc.balance + m.balance,
+                      acc.balance + m.balance,
+                      acc.balance_gap + m.balance_gap,
                       acc.overflow + m.overflow.astype(jnp.float32),
                       acc.expert_load + m.expert_load)
 
@@ -380,17 +387,18 @@ def forward(params, cfg: ArchConfig, batch: dict, rt: Runtime = _NULL_RT,
     it); ``last_only`` computes logits for the final position only (serving
     prefill — the decode loop needs just the next-token distribution).
     """
-    if "embeds" in batch and batch["embeds"] is not None:
-        x = batch["embeds"]
-        b, t, _ = x.shape
-    else:
-        tokens = batch["tokens"]
-        b, t = tokens.shape
-        x = params["embed"][tokens]
+    with jax.named_scope(scopes.EMBED):
+        if "embeds" in batch and batch["embeds"] is not None:
+            x = batch["embeds"]
+            b, t, _ = x.shape
+        else:
+            tokens = batch["tokens"]
+            b, t = tokens.shape
+            x = params["embed"][tokens]
+        x = rt.constrain(x, "act")
     positions = batch.get("positions")
     if positions is None:
         positions = _default_positions(cfg, b, t)
-    x = rt.constrain(x, "act")
 
     reps, rem = _pattern_counts(cfg)
     pat = cfg.pattern
@@ -438,7 +446,8 @@ def forward(params, cfg: ArchConfig, batch: dict, rt: Runtime = _NULL_RT,
             st_out = jax.tree_util.tree_map(
                 lambda *leaves: jnp.stack(leaves), *outs)
         else:
-            (x, acc), st_out = jax.lax.scan(body, (x, acc), xs)
+            with jax.named_scope(scopes.LAYERS):
+                (x, acc), st_out = jax.lax.scan(body, (x, acc), xs)
         new_states["scan"] = st_out
 
     if rem > 0:
@@ -452,16 +461,18 @@ def forward(params, cfg: ArchConfig, batch: dict, rt: Runtime = _NULL_RT,
             new_rem.append(s)
         new_states["rem"] = tuple(new_rem)
 
-    x = _norm(cfg, params["final_norm"], x)
+    with jax.named_scope(scopes.LM_HEAD_LOSS):
+        x = _norm(cfg, params["final_norm"], x)
     if not cfg.moe:
         new_states = solver_states   # keep carry structure for scan loops
     if return_hidden:
         return x, acc, new_states
-    head = params.get("head")
-    w_out = head if head is not None else params["embed"].T
-    if last_only:
-        x = x[:, -1:]
-    logits = rt.constrain(x @ w_out, "logits")
+    with jax.named_scope(scopes.LM_HEAD_LOSS):
+        head = params.get("head")
+        w_out = head if head is not None else params["embed"].T
+        if last_only:
+            x = x[:, -1:]
+        logits = rt.constrain(x @ w_out, "logits")
     return logits, acc, new_states
 
 
@@ -557,18 +568,20 @@ def loss_fn(params, cfg: ArchConfig, batch: dict, rt: Runtime = _NULL_RT,
     — the training-side feed for the telemetry recorder (TELEMETRY.md)."""
     hidden, moe, new_states = forward(params, cfg, batch, rt, solver_states,
                                       return_hidden=True)
-    head = params.get("head")
-    w_out = head if head is not None else params["embed"].T
-    ce = lm_loss_chunked(hidden, w_out, batch["labels"],
-                         chunk_t=loss_chunk_t, unroll=rt.unroll,
-                         constrain=rt.shard)
+    with jax.named_scope(scopes.LM_HEAD_LOSS):
+        head = params.get("head")
+        w_out = head if head is not None else params["embed"].T
+        ce = lm_loss_chunked(hidden, w_out, batch["labels"],
+                             chunk_t=loss_chunk_t, unroll=rt.unroll,
+                             constrain=rt.shard)
     n_moe = max(sum(1 for k in cfg.pattern if k.startswith("attn")), 1) \
         * max(_pattern_counts(cfg)[0], 1) if cfg.moe else 1
     loss = ce + aux_coeff * moe.aux_loss + z_coeff * moe.z_loss
     metrics = Metrics(loss=loss, ce_loss=ce, aux_loss=moe.aux_loss,
                       z_loss=moe.z_loss,
                       balance=moe.balance / n_moe,
-                      overflow=moe.overflow)
+                      overflow=moe.overflow,
+                      balance_gap=moe.balance_gap / n_moe)
     if with_expert_load:
         return loss, (metrics, new_states, moe.expert_load)
     return loss, (metrics, new_states)

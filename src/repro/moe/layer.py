@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import scopes
 from ..core.scheduler import MicroEPScheduler, ScheduleStatics
 from ..core.solver_jax import SolverState
 from . import dispatch as D
@@ -45,10 +46,11 @@ __all__ = ["MoEMetrics", "moe_ffn", "MoEFFNSpec"]
 class MoEMetrics(NamedTuple):
     aux_loss: jax.Array
     z_loss: jax.Array
-    max_load: jax.Array      # scheduled max device load (tokens)
     balance: jax.Array       # max / mean device load; on a heterogeneous
                              # group (device profiles, DESIGN.md §11) the
                              # max is over weight-normalized loads L_g/w_g
+    balance_gap: jax.Array   # that max over the LP optimum (Eq. 3): 1 when
+                             # the schedule reaches what the placement allows
     overflow: jax.Array      # rows dropped to residual by capacity clipping
     expert_load: jax.Array   # f32[E] group-wide routed tokens per expert
                              # (feeds the serving replacement manager;
@@ -114,12 +116,11 @@ def moe_ffn(
         x, w_router, k, valid=valid
     )
 
-    # token-replica rows: [T*K]
-    ex = r.expert_ids.reshape(-1)
-    rows = jnp.repeat(x, k, axis=0)
-
-    cnt = jnp.zeros(st.num_experts + 1, jnp.int32).at[ex].add(1)[: st.num_experts]
-    input_eg = _gather_counts(cnt, spec.group_axes)          # [E, G]
+    with jax.named_scope(scopes.COUNTS):
+        ex = r.expert_ids.reshape(-1)                        # [T*K]
+        cnt = jnp.zeros(st.num_experts + 1, jnp.int32).at[ex].add(1)[
+            : st.num_experts]
+        input_eg = _gather_counts(cnt, spec.group_axes)      # [E, G]
 
     sched = spec.scheduler(input_eg, state,
                            mem_caps=None if spec.mem_caps is None
@@ -134,41 +135,51 @@ def moe_ffn(
     if n_stages > 1:
         # destination-chunked pipelined hot path: chunk c's FFN depends
         # only on stage c's collective, so compute overlaps communication
-        plan = D.make_chunked_plan(st, ex, sched.flow, my_index, n_stages)
-        flat_chunks = D.dispatch_pipelined(
-            st, plan, rows, spec.group_axes, my_index,
-            chunk_comm=spec.chunk_comm)
-        out_chunks = expert_ffn_flat_chunked(
-            flat_chunks, plan.group_start, plan.group_end, experts,
-            spec.activation, impl=spec.kernel_impl, bm=st.bm,
-        )
-        if spec.tp_axis is not None:
-            out_chunks = tuple(jax.lax.psum(o, spec.tp_axis)
-                               for o in out_chunks)
-        out_rows = D.combine_pipelined(
-            st, plan, out_chunks, spec.group_axes, my_index,
-            chunk_comm=spec.chunk_comm)
+        with jax.named_scope(scopes.DISPATCH):
+            rows = jnp.repeat(x, k, axis=0)            # token-replica rows
+            plan = D.make_chunked_plan(st, ex, sched.flow, my_index,
+                                       n_stages)
+            flat_chunks = D.dispatch_pipelined(
+                st, plan, rows, spec.group_axes, my_index,
+                chunk_comm=spec.chunk_comm)
+        with jax.named_scope(scopes.EXPERT_FFN):
+            out_chunks = expert_ffn_flat_chunked(
+                flat_chunks, plan.group_start, plan.group_end, experts,
+                spec.activation, impl=spec.kernel_impl, bm=st.bm,
+            )
+            if spec.tp_axis is not None:
+                out_chunks = tuple(jax.lax.psum(o, spec.tp_axis)
+                                   for o in out_chunks)
+        with jax.named_scope(scopes.COMBINE):
+            out_rows = D.combine_pipelined(
+                st, plan, out_chunks, spec.group_axes, my_index,
+                chunk_comm=spec.chunk_comm)
     else:
-        plan = D.make_plan(st, ex, sched.flow, my_index)
-        flat = D.dispatch(st, plan, rows, spec.group_axes,
-                          mode=spec.dispatch_mode)
-        out_flat = expert_ffn_flat(
-            flat, plan.group_start, plan.group_end, experts,
-            spec.activation, impl=spec.kernel_impl, bm=st.bm,
-        )
-        if spec.tp_axis is not None:
-            out_flat = jax.lax.psum(out_flat, spec.tp_axis)
-        out_rows = D.combine(st, plan, out_flat, spec.group_axes,
-                             mode=spec.dispatch_mode)
+        with jax.named_scope(scopes.DISPATCH):
+            rows = jnp.repeat(x, k, axis=0)            # token-replica rows
+            plan = D.make_plan(st, ex, sched.flow, my_index)
+            flat = D.dispatch(st, plan, rows, spec.group_axes,
+                              mode=spec.dispatch_mode)
+        with jax.named_scope(scopes.EXPERT_FFN):
+            out_flat = expert_ffn_flat(
+                flat, plan.group_start, plan.group_end, experts,
+                spec.activation, impl=spec.kernel_impl, bm=st.bm,
+            )
+            if spec.tp_axis is not None:
+                out_flat = jax.lax.psum(out_flat, spec.tp_axis)
+        with jax.named_scope(scopes.COMBINE):
+            out_rows = D.combine(st, plan, out_flat, spec.group_axes,
+                                 mode=spec.dispatch_mode)
 
-    out = (out_rows.reshape(t, k, h) * r.gate_w[:, :, None].astype(x.dtype)
-           ).sum(axis=1)
+    with jax.named_scope(scopes.COMBINE):
+        out = (out_rows.reshape(t, k, h)
+               * r.gate_w[:, :, None].astype(x.dtype)).sum(axis=1)
 
     metrics = MoEMetrics(
         aux_loss=r.aux_loss,
         z_loss=r.z_loss,
-        max_load=sched.max_load,
         balance=sched.balance,
+        balance_gap=sched.balance_gap,
         overflow=plan.overflow,
         expert_load=input_eg.sum(axis=1).astype(jnp.float32),
     )

@@ -12,6 +12,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .. import scopes
+
 __all__ = ["RouterOut", "top_k_gating", "zipf_gating"]
 
 
@@ -23,6 +25,7 @@ class RouterOut(NamedTuple):
     probs: jax.Array        # f32[T, E] full router probabilities
 
 
+@jax.named_scope(scopes.ROUTER)
 def top_k_gating(
     x: jax.Array,          # [T, H]
     w_router: jax.Array,   # [H, E]

@@ -27,6 +27,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import scopes
 from ..configs.base import ArchConfig
 from ..models import decoder as dec
 from ..optim.adamw import AdamWConfig, AdamWState, adamw_init, adamw_update
@@ -105,9 +106,9 @@ def make_train_step(
         raise ValueError("with_expert_load=True needs an MoE config")
 
     def train_step(ts: TrainState, batch: dict):
-        params, vjp_fn = jax.vjp(hooks.to_working, ts.master)
+        with jax.named_scope(scopes.OPTIMIZER):
+            params, vjp_fn = jax.vjp(hooks.to_working, ts.master)
         micro = _split_micro(batch, n_micro)
-        zero_g = jax.tree_util.tree_map(jnp.zeros_like, params)
 
         def micro_fn(carry, mb):
             solver, gsum, msum, esum = carry
@@ -126,32 +127,38 @@ def make_train_step(
                 lambda a, b: a + b.astype(jnp.float32), msum, metrics)
             return (new_solver, gsum, msum, esum), None
 
-        zero_m = dec.Metrics(*(jnp.zeros(()) for _ in range(6)))
-        zero_e = jnp.zeros((cfg.num_experts * max(cfg.etp, 1),)
-                           if with_expert_load else ())
-        (solver, gsum, msum, esum), _ = jax.lax.scan(
-            micro_fn, (ts.solver, zero_g, zero_m, zero_e), micro)
+        # the loop's own work (carries, sums) is the scope's; the model's
+        # layers inside it carry their own, innermost scopes
+        with jax.named_scope(scopes.GRAD_ACCUM):
+            zero_g = jax.tree_util.tree_map(jnp.zeros_like, params)
+            zero_m = dec.Metrics(*(jnp.zeros(())
+                                   for _ in dec.Metrics._fields))
+            zero_e = jnp.zeros((cfg.num_experts * max(cfg.etp, 1),)
+                               if with_expert_load else ())
+            (solver, gsum, msum, esum), _ = jax.lax.scan(
+                micro_fn, (ts.solver, zero_g, zero_m, zero_e), micro)
+            gavg = jax.tree_util.tree_map(lambda g: g / n_micro, gsum)
+            mavg = jax.tree_util.tree_map(lambda x: x / n_micro, msum)
+        with jax.named_scope(scopes.OPTIMIZER):
+            (master_grads,) = vjp_fn(gavg)
+            if master_grad_constraint is not None:
+                # pin grads to the (ZeRO-1-sharded) master layout so GSPMD
+                # lowers the data-parallel reduction as reduce-scatter
+                # rather than all-reduce + slice (§Perf lever)
+                master_grads = master_grad_constraint(master_grads)
+            lr = lr_fn(ts.opt.step) if lr_fn is not None else None
+            new_master, new_opt, gnorm = adamw_update(
+                master_grads, ts.opt, ts.master, opt_cfg, lr=lr)
+            new_ts = TrainState(master=new_master, opt=new_opt,
+                                solver=solver, step=ts.step + 1)
 
-        gavg = jax.tree_util.tree_map(lambda g: g / n_micro, gsum)
-        (master_grads,) = vjp_fn(gavg)
-        if master_grad_constraint is not None:
-            # pin grads to the (ZeRO-1-sharded) master layout so GSPMD
-            # lowers the data-parallel reduction as reduce-scatter rather
-            # than all-reduce + slice (§Perf lever)
-            master_grads = master_grad_constraint(master_grads)
-        lr = lr_fn(ts.opt.step) if lr_fn is not None else None
-        new_master, new_opt, gnorm = adamw_update(
-            master_grads, ts.opt, ts.master, opt_cfg, lr=lr)
-
-        mavg = jax.tree_util.tree_map(lambda x: x / n_micro, msum)
         out = {"loss": mavg.loss, "ce_loss": mavg.ce_loss,
                "aux_loss": mavg.aux_loss, "z_loss": mavg.z_loss,
-               "balance": mavg.balance, "overflow": msum.overflow,
-               "grad_norm": gnorm,
+               "balance": mavg.balance, "balance_gap": mavg.balance_gap,
+               "overflow": msum.overflow, "grad_norm": gnorm,
                "lr": jnp.asarray(lr if lr is not None else opt_cfg.lr)}
         if with_expert_load:
             out["expert_load"] = esum
-        return TrainState(master=new_master, opt=new_opt, solver=solver,
-                          step=ts.step + 1), out
+        return new_ts, out
 
     return train_step
