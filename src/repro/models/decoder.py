@@ -96,6 +96,7 @@ class Metrics(NamedTuple):
     balance: jax.Array    # mean over MoE layers of max/mean device load
     overflow: jax.Array   # total capacity-overflow rows (0 in practice)
     balance_gap: jax.Array  # mean over MoE layers of max load / LP optimum
+    flat_fill: jax.Array    # mean over MoE layers of routed rows / flat rows
 
 
 # --------------------------------------------------------------------------
@@ -294,18 +295,17 @@ def _moe_block(p_moe, x, cfg: ArchConfig, rt: Runtime, state, valid=None):
     return out2d.reshape(b, t, h), metrics, new_state
 
 
-_ZERO_MOE = MoEMetrics(*(jnp.zeros(()) for _ in range(6)))
+_ZERO_MOE = MoEMetrics(*(jnp.zeros(()) for _ in MoEMetrics._fields))
 
 
 def _zero_moe(cfg: ArchConfig) -> MoEMetrics:
     """Shape-correct zero metrics accumulator: ``expert_load`` is [E_virt]
     for MoE configs so scan carries stay shape-stable under accumulation
     (dense-layer zeros broadcast into it)."""
-    z = jnp.zeros(())
     if not cfg.moe:
         return _ZERO_MOE
     e = jnp.zeros((cfg.num_experts * max(cfg.etp, 1),))
-    return MoEMetrics(z, z, z, z, z, e)
+    return _ZERO_MOE._replace(expert_load=e)
 
 
 def n_moe_layers(cfg: ArchConfig) -> int:
@@ -364,6 +364,7 @@ def _accum(acc, m: MoEMetrics):
     return MoEMetrics(acc.aux_loss + m.aux_loss, acc.z_loss + m.z_loss,
                       acc.balance + m.balance,
                       acc.balance_gap + m.balance_gap,
+                      acc.flat_fill + m.flat_fill,
                       acc.overflow + m.overflow.astype(jnp.float32),
                       acc.expert_load + m.expert_load)
 
@@ -581,7 +582,8 @@ def loss_fn(params, cfg: ArchConfig, batch: dict, rt: Runtime = _NULL_RT,
                       z_loss=moe.z_loss,
                       balance=moe.balance / n_moe,
                       overflow=moe.overflow,
-                      balance_gap=moe.balance_gap / n_moe)
+                      balance_gap=moe.balance_gap / n_moe,
+                      flat_fill=moe.flat_fill / n_moe)
     if with_expert_load:
         return loss, (metrics, new_states, moe.expert_load)
     return loss, (metrics, new_states)

@@ -14,6 +14,15 @@ NCCL all-to-all — see DESIGN.md §2):
   flat buffer  [N_flat,  H]    rows sorted by local expert slot, bm-aligned
                                group starts (grouped-FFN layout)
 
+  N_flat = (G-1)·cap + C_in + S·bm, rounded up to bm: the rows a device can
+  receive — at most ``cap`` from each of the G-1 other sources (senders
+  clip there, and the receiver's layout clips its segments the same way)
+  plus its own C_in local rows, which never enter the exchange — and the
+  alignment slack.  The device's own chunk of the recv buffer is never
+  used; a group of one device (G = 1) has no remote path at all, so
+  ``dispatch``/``combine`` build no send or recv buffer and run no
+  all-to-all there.
+
 **Locality fast path** (paper §5.2 locality-aware routing): rows whose
 scheduled replica lives on their own device never enter the all-to-all —
 they are scattered straight into the flat buffer.  This is both the
@@ -142,9 +151,11 @@ def build_statics(
 
 
 def flat_buffer_size(st: DispatchStatics) -> int:
-    """Rows of the slot-sorted flat buffer: remote recv rows + own local rows
-    + per-group bm alignment slack, rounded up to a bm multiple."""
-    n = st.group_size * st.cap + st.c_in + st.num_slots * st.bm
+    """Rows of the slot-sorted flat buffer: up to ``cap`` rows from each of
+    the G-1 other devices + the C_in own local rows + per-group bm
+    alignment slack, rounded up to a bm multiple.  Equals
+    ``chunk_caps(st, 1)[0]``."""
+    n = (st.group_size - 1) * st.cap + st.c_in + st.num_slots * st.bm
     return int(np.ceil(n / st.bm) * st.bm)
 
 
@@ -168,8 +179,8 @@ def chunk_caps(st: DispatchStatics, n_stages: int) -> tuple:
     — no capacity clipping applies locally) plus m-1 remote cap-chunks;
     chunks 1..n-1 carry m remote cap-chunks each.  Every chunk pays up to
     S·bm alignment slack for its own bm-aligned group starts, so the
-    pipelined buffer totals  G·cap + C_in + n·S·bm  rows before rounding —
-    (n-1)·S·bm more than the monolithic layout (DESIGN.md §2)."""
+    pipelined buffer totals  (G-1)·cap + C_in + n·S·bm  rows before
+    rounding — (n-1)·S·bm more than the monolithic layout (DESIGN.md §2)."""
     m = st.group_size // n_stages
     bm = st.bm
 
@@ -285,11 +296,21 @@ def _recv_segments(st: DispatchStatics, flow: jax.Array,
     slots: recv_seg[g, s] = flow[exp_of[me, s], g, rep_of[me, s]].  The
     (src, dst) within-chunk layout both plans derive from this is the
     contract the sender's `_sender_layout` fills against.  Empty
-    (budgeted) slots have exp_of = -1 and receive nothing."""
+    (budgeted) slots have exp_of = -1 and receive nothing.
+
+    A remote source's segments are clipped to its ``cap`` window, as its
+    sender clips (rows at chunk offset >= cap overflow to the residual):
+    clipping leaves every segment start below ``cap`` where it was, and
+    bounds the rows a device receives by (G-1)·cap + C_in.  The own
+    source's row is the local fast path and is never clipped."""
     exp_of = jnp.asarray(st.exp_of_dev_slot, jnp.int32)[my_index]   # [S]
     rep_of = jnp.asarray(st.rep_of_dev_slot, jnp.int32)[my_index]
     seg = flow[jnp.maximum(exp_of, 0), :, rep_of]                   # [S, G]
-    return jnp.where(exp_of[None, :] >= 0, seg.T, 0)
+    seg = jnp.where(exp_of[None, :] >= 0, seg.T, 0)                 # [G, S]
+    end = jnp.cumsum(seg, axis=1)
+    clipped = jnp.minimum(end, st.cap) - jnp.minimum(end - seg, st.cap)
+    src = jnp.arange(seg.shape[0], dtype=jnp.int32)[:, None]
+    return jnp.where(src == my_index, seg, clipped)
 
 
 def _chunk_row_slots(seg_start: jax.Array, seg: jax.Array, cap: int):
@@ -399,16 +420,18 @@ def dispatch(
     ``mode="packed"`` builds the buffers with int32-scatter + row gathers
     (no zero-buffer materialization); ``mode="scatter"`` is the legacy
     dense ``.at[].set`` path kept for the bench comparison.  Both are
-    bit-identical."""
+    bit-identical.  A group of one device (or no ``group_axes``) has only
+    local rows: no send buffer, no all-to-all."""
     g_n, cap, h = st.group_size, st.cap, rows.shape[-1]
     c_in = rows.shape[0]
     n_flat = flat_buffer_size(st)
+    remote = bool(group_axes) and g_n > 1
     if mode == "scatter":
         flat = jnp.zeros((n_flat + 1, h), rows.dtype)
         # local fast path: no collective
         flat = flat.at[plan.local_pos].set(
             jnp.where(plan.is_local[:, None], rows, 0))
-        if group_axes:
+        if remote:
             send = jnp.zeros((g_n * cap + 1, h), rows.dtype)
             send = send.at[plan.send_pos].set(rows)[: g_n * cap]
             recv = jax.lax.all_to_all(
@@ -424,7 +447,7 @@ def dispatch(
     # packed: the only scatters move int32 indices; rows move via gathers.
     # flat sources: [0, C_in) = local rows, [C_in, C_in + G*cap) = recv
     # rows, C_in + G*cap = the zero row.
-    if group_axes:
+    if remote:
         send_src = _inverse_index(plan.send_pos, g_n * cap, c_in)
         send = _gather_rows(rows, send_src)               # [G*cap, H]
         recv = jax.lax.all_to_all(
@@ -452,11 +475,12 @@ def combine(
 ) -> jax.Array:
     """Inverse of dispatch: returns per-local-row outputs [C_in, H]."""
     g_n, cap, h = st.group_size, st.cap, flat_out.shape[-1]
+    remote = bool(group_axes) and g_n > 1
     if mode == "scatter":
         pad = jnp.zeros((1, h), flat_out.dtype)
         flat_padded = jnp.concatenate([flat_out, pad])
         out_local = flat_padded[plan.local_pos]               # [C_in, H]
-        if group_axes:
+        if remote:
             recv = flat_padded[plan.flat_pos]                 # [G*cap, H]
             send = jax.lax.all_to_all(
                 recv.reshape(g_n, cap, h), tuple(group_axes),
@@ -468,7 +492,7 @@ def combine(
             out_remote = jnp.zeros_like(out_local)
     elif mode == "packed":
         out_local = _gather_rows(flat_out, plan.local_pos)    # [C_in, H]
-        if group_axes:
+        if remote:
             recv = _gather_rows(flat_out, plan.flat_pos)      # [G*cap, H]
             send = jax.lax.all_to_all(
                 recv.reshape(g_n, cap, h), tuple(group_axes),

@@ -3,7 +3,8 @@ first-class module.
 
 ``moe_ffn`` is a *per-device* function (call it inside shard_map; or with
 ``group_axes=()`` on a single device — the degenerate G=1 group used by CPU
-smoke tests).  Steps (paper §4 "Runtime"):
+smoke tests; a one-device group under shard_map computes the same, with no
+exchange).  Steps (paper §4 "Runtime"):
 
   gate -> counts all-gather -> schedule (LP solve + rounding + Alg.1 routing)
        -> dispatch all-to-all -> grouped expert FFN -> combine all-to-all
@@ -51,6 +52,8 @@ class MoEMetrics(NamedTuple):
                              # max is over weight-normalized loads L_g/w_g
     balance_gap: jax.Array   # that max over the LP optimum (Eq. 3): 1 when
                              # the schedule reaches what the placement allows
+    flat_fill: jax.Array     # rows holding routed tokens over the flat
+                             # buffer's rows (the rest is padding)
     overflow: jax.Array      # rows dropped to residual by capacity clipping
     expert_load: jax.Array   # f32[E] group-wide routed tokens per expert
                              # (feeds the serving replacement manager;
@@ -132,6 +135,7 @@ def moe_ffn(
 
     n_stages = D.effective_stages(spec.pipeline_stages, st.group_size) \
         if spec.group_axes else 1
+    flat_rows = sum(D.chunk_caps(st, n_stages))
     if n_stages > 1:
         # destination-chunked pipelined hot path: chunk c's FFN depends
         # only on stage c's collective, so compute overlaps communication
@@ -180,6 +184,7 @@ def moe_ffn(
         z_loss=r.z_loss,
         balance=sched.balance,
         balance_gap=sched.balance_gap,
+        flat_fill=jnp.sum(plan.group_end - plan.group_start) / flat_rows,
         overflow=plan.overflow,
         expert_load=input_eg.sum(axis=1).astype(jnp.float32),
     )

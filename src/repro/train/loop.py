@@ -155,6 +155,7 @@ def make_train_step(
         out = {"loss": mavg.loss, "ce_loss": mavg.ce_loss,
                "aux_loss": mavg.aux_loss, "z_loss": mavg.z_loss,
                "balance": mavg.balance, "balance_gap": mavg.balance_gap,
+               "flat_fill": mavg.flat_fill,
                "overflow": msum.overflow, "grad_norm": gnorm,
                "lr": jnp.asarray(lr if lr is not None else opt_cfg.lr)}
         if with_expert_load:

@@ -12,6 +12,7 @@ Three equivalence families, all hard gates for perf-path refactors:
     for both chunk collectives (ppermute and a2a).
 """
 import os
+import re
 import subprocess
 import sys
 
@@ -241,7 +242,162 @@ def test_chunk_caps_accounting():
         total = sum(caps)
         # within one bm round-up per chunk of the monolithic size + slack
         assert total <= mono + (n - 1) * st.num_slots * st.bm + n * st.bm
-        assert total >= st.group_size * st.cap
+        assert total >= (st.group_size - 1) * st.cap + st.c_in
+
+
+# ------------------------- flat buffer sized to the rows a device receives
+
+_GRIDS = {1: (1, 1), 2: (1, 2), 4: (1, 4)}
+
+
+@pytest.mark.parametrize("g", [1, 2, 4])
+def test_flat_buffer_size_is_receive_bound(g):
+    """(G-1)·cap remote rows + C_in own rows + S·bm slack, rounded to bm;
+    the monolithic buffer is the one-stage pipelined layout."""
+    eng = MicroEPEngine.build(8, _GRIDS[g], placement="latin")
+    st = eng.dispatch_statics(48, 2, 2.0, 8)
+    raw = (g - 1) * st.cap + st.c_in + st.num_slots * st.bm
+    assert D.flat_buffer_size(st) == -(-raw // st.bm) * st.bm
+    assert D.chunk_caps(st, 1) == (D.flat_buffer_size(st),)
+
+
+def _vanilla_statics(g):
+    """One replica per expert on a (1, G) grid, C_in = 32, cap = 32/G."""
+    eng = MicroEPEngine.build(2 * g, (1, g), placement="vanilla")
+    return eng.dispatch_statics(16, 2, 1.0, 8)
+
+
+def _vanilla_exchange(st, sent_to_0):
+    """G devices simulated by ``vmap`` over a named axis: device 0 keeps
+    its C_in rows local, every other device sends ``sent_to_0`` rows to
+    device 0 (one replica per expert, so the flow is the counts) and keeps
+    the rest.  Returns plans, flat buffers, the round trip and the round
+    trip of the two-stage pipelined path."""
+    g = st.group_size
+    hosted = st.exp_of_dev_slot                       # [G, S]
+    ex = np.stack([np.resize(hosted[0], st.c_in)] + [
+        np.concatenate([np.resize(hosted[0], sent_to_0),
+                        np.resize(hosted[d], st.c_in - sent_to_0)])
+        for d in range(1, g)]).astype(np.int32)       # [G, C_in]
+    flow = np.zeros((st.num_experts, g, 1), np.int32)
+    for d in range(g):
+        np.add.at(flow[:, d, 0], ex[d], 1)
+    rows = (jnp.arange(g * st.c_in, dtype=jnp.float32) + 1.0).reshape(
+        g, st.c_in, 1) * jnp.ones((1, 1, 4))
+
+    def device(ex_d, rows_d):
+        me = jax.lax.axis_index("g").astype(jnp.int32)
+        plan = D.make_plan(st, ex_d, jnp.asarray(flow), me)
+        flat = D.dispatch(st, plan, rows_d, ("g",))
+        cplan = D.make_chunked_plan(st, ex_d, jnp.asarray(flow), me, 2)
+        chunks = D.dispatch_pipelined(st, cplan, rows_d, ("g",), me)
+        return (plan, flat, D.combine(st, plan, flat, ("g",)),
+                D.combine_pipelined(st, cplan, chunks, ("g",), me))
+
+    plan, flat, back, back2 = jax.vmap(device, axis_name="g")(
+        jnp.asarray(ex), rows)
+    return (plan, np.asarray(flat), np.asarray(back), np.asarray(back2),
+            np.asarray(rows))
+
+
+@pytest.mark.parametrize("g,over", [(2, 0), (2, 5), (4, 0), (4, 3),
+                                    (4, None)])
+def test_full_receive_fits_flat_buffer(g, over):
+    """Device 0 receives a full ``cap`` from every other source (``over``
+    more rows sent beyond it; None = every row of every source) plus all
+    C_in of its own.  Overflow comes only from sender clipping, every
+    dispatched row lands in the flat buffer, none in the trash slot, and
+    the round trip, monolithic and pipelined, returns each of them."""
+    st = _vanilla_statics(g)
+    cap, n_flat = st.cap, D.flat_buffer_size(st)
+    sent_to_0 = cap + over if over is not None else st.c_in
+    plan, flat, back, back2, rows = _vanilla_exchange(st, sent_to_0)
+    assert np.asarray(plan.overflow).sum() == (g - 1) * (sent_to_0 - cap)
+    assert int(plan.overflow[0]) == 0
+    assert int(jnp.sum(plan.local_pos[0] < n_flat)) == st.c_in
+    assert int(jnp.sum(plan.flat_pos[0] < n_flat)) == (g - 1) * cap
+    assert int(plan.group_end[0].max()) <= n_flat
+    valid = np.asarray(plan.valid)
+    np.testing.assert_array_equal(back, np.where(valid[..., None], rows, 0))
+    np.testing.assert_array_equal(back2, back)
+    # device 0's buffer holds exactly the dispatched rows sent to it
+    to_0 = np.zeros_like(valid)
+    to_0[0] = True
+    to_0[1:, :sent_to_0] = True
+    want = np.sort(rows[valid & to_0][:, 0])
+    got = np.sort(flat[0][flat[0, :, 0] != 0][:, 0])
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("g", [1, 2, 4])
+def test_flat_fill_counter(g):
+    """``flat_fill`` (routed rows over flat rows) lies in (0, 1]; on a
+    one-device group without overflow it is C_in / N_flat."""
+    e, t, k, h = 8, 24, 2, 16
+    eng = MicroEPEngine.build(e, _GRIDS[g], placement="latin")
+    spec = eng.moe_spec(t, k, activation="swiglu", group_axes=("g",),
+                        capacity_factor=2.0, bm=8, kernel_impl="ref")
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    x = jax.random.normal(ks[0], (g, t, h), jnp.float32) * 0.5
+    w_router = jax.random.normal(ks[1], (h, e)) * 0.1
+    canon = init_canonical_experts(ks[2], e, h, 24)
+    table = jnp.asarray(eng.placement.flat())         # [G, S]
+    work = jax.tree_util.tree_map(lambda w: w[table], canon)
+
+    def device(x_d, exp_d):
+        _, m, _ = moe_ffn(spec, x_d, w_router, exp_d)
+        return m
+
+    m = jax.vmap(device, axis_name="g")(x, work)
+    fill = np.asarray(m.flat_fill)
+    assert ((fill > 0) & (fill <= 1)).all(), fill
+    if g == 1:
+        assert int(m.overflow[0]) == 0
+        assert float(fill[0]) == np.float32(
+            spec.statics.c_in / D.flat_buffer_size(spec.statics))
+
+
+def test_one_device_group_has_no_exchange():
+    """A 1x1 mesh with group_axes=("data", "model") computes what the
+    group_axes=() path computes, bit for bit, forward and backward, and
+    its step holds no all-to-all and no H-wide send or receive buffer
+    (G·cap rows)."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_local_mesh
+
+    _, spec0, x, w_router, experts = _local_setup(jax.random.PRNGKey(11))
+    spec1 = spec0._replace(group_axes=("data", "model"))
+    mesh = make_local_mesh(1, 1)
+
+    def loss_local(x, experts):
+        out, _, _ = moe_ffn(spec0, x, w_router, experts)
+        return jnp.sum(out ** 2), out
+
+    def loss_mesh(x, experts):
+        def inner(x_loc, exp_loc):
+            out, _, _ = moe_ffn(spec1, x_loc, w_router, exp_loc)
+            return out
+        out = shard_map(inner, mesh=mesh,
+                        in_specs=(P(("data", "model")), P()),
+                        out_specs=P(("data", "model")),
+                        check_vma=False)(x, experts)
+        return jnp.sum(out ** 2), out
+
+    grad = lambda f: jax.jit(jax.grad(f, argnums=(0, 1), has_aux=True))
+    (gx0, ge0), out0 = grad(loss_local)(x, experts)
+    (gx1, ge1), out1 = grad(loss_mesh)(x, experts)
+    np.testing.assert_array_equal(np.asarray(out1), np.asarray(out0))
+    np.testing.assert_array_equal(np.asarray(gx1), np.asarray(gx0))
+    for a, b in zip(jax.tree_util.tree_leaves(ge1),
+                    jax.tree_util.tree_leaves(ge0)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    lowered = grad(loss_mesh).lower(x, experts)
+    compiled = lowered.compile().as_text()
+    assert "all_to_all" not in lowered.as_text()
+    assert "all-to-all" not in compiled
+    st = spec1.statics
+    assert not re.search(rf"f32\[(1,)?{st.cap},{x.shape[1]}\]", compiled)
 
 
 # -------------------------------- pipelined == monolithic on shard_map mesh
